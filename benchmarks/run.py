@@ -20,6 +20,7 @@ sections.
 from __future__ import annotations
 
 import argparse
+import os
 import sys
 import time
 import traceback
@@ -93,7 +94,8 @@ def main() -> None:
                          "and append a trajectory record; '' disables")
     args = ap.parse_args()
 
-    sys.path.insert(0, "/root/repo/src")
+    sys.path.insert(0, os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src"))
     from benchmarks import bench_kernels, bench_paper
 
     print("name,us_per_call,derived")

@@ -451,7 +451,9 @@ class UlisseEngine:
         from repro.core.index import default_breakpoints
         from repro.distributed.ulisse import shard_collection
 
-        data = jnp.asarray(data, jnp.float32)
+        # host-side: device_put from host places each shard on its own
+        # device, never the whole collection on one
+        data = np.asarray(data, np.float32)
         # fail before sharding/breakpoint work (jax's own device_put
         # divisibility error is far less actionable)
         _require_divisible(int(data.shape[0]), mesh, axes)
@@ -722,6 +724,24 @@ class UlisseEngine:
     def index(self) -> Optional[UlisseIndex]:
         """The local index (None for the distributed backend)."""
         return self._index
+
+    def device_arrays(self) -> dict:
+        """The device-resident collection and index arrays by field name
+        (row-sharded over the mesh on the distributed backend, which
+        builds them here if no query has yet) — what the engine keeps
+        on its devices, for memory accounting."""
+        if self.is_distributed:
+            from repro.distributed.ulisse import SHARDED_INDEX_FIELDS
+            return dict(zip(SHARDED_INDEX_FIELDS,
+                            self._ensure_sharded_index()))
+        coll = self._index.collection
+        env = self._index.search_envelopes()
+        out = {f: getattr(coll, f) for f in (
+            "data", "csum", "csum2", "csum_lo", "csum2_lo", "center")}
+        out.update({f: getattr(env, f) for f in (
+            "paa_lo", "paa_hi", "sym_lo", "sym_hi", "series_id",
+            "anchor", "n_master", "valid")})
+        return out
 
     @property
     def raw_data(self) -> np.ndarray:
@@ -1007,7 +1027,17 @@ class UlisseEngine:
 
     def _local_approx(self, q, spec: QuerySpec) -> SearchResult:
         pool, stats, _ = self._local_approx_impl(q, spec)
-        return pool.result(stats)
+        return self._host_knn_result(q, spec, pool, stats)
+
+    def _host_knn_result(self, q, spec: QuerySpec, pool,
+                         stats) -> SearchResult:
+        """The host pool as a result: reported ED distances go through
+        the same float64 rescore as every device path.  Unfilled pool
+        rows (+inf: k exceeded the valid windows) are dropped, as the
+        device paths drop theirs."""
+        real = np.isfinite(pool.d)
+        return self._knn_result_rows(q, spec, pool.d[real], pool.s[real],
+                                     pool.o[real], stats)
 
     def _local_approx_impl(self, q, spec: QuerySpec,
                            pq: Optional[planner.PreparedQuery] = None):
@@ -1097,7 +1127,7 @@ class UlisseEngine:
             # re-pushing sqrt(d2)**2 perturbs exact-tie pruning
             pool, stats, _ = self._local_approx_impl(q, spec, pq)
             if stats.exact_from_approx:
-                return pool.result(stats)
+                return self._host_knn_result(q, spec, pool, stats)
         else:
             stats = SearchStats(
                 envelopes_total=int(index.search_envelopes().size))
@@ -1126,7 +1156,7 @@ class UlisseEngine:
             stats.envelopes_pruned += int((fin & ~keep).sum())
             stats.chunks_visited += 1
             pos = end
-        return pool.result(stats)
+        return self._host_knn_result(q, spec, pool, stats)
 
     # -- the one-sync device pipeline (DESIGN.md §8/§9) ----------------
 
@@ -1220,7 +1250,7 @@ class UlisseEngine:
         # leaf cannot improve the pool, or no finite-LB leaf is left
         kth2 = ad2[:, k - 1]
         next_lb = blk_sorted[jnp.arange(b),
-                             jnp.minimum(leaf_v, nblk - 1)]
+                             jnp.minimum(leaf_v, blk_sorted.shape[1] - 1)]
         cert = ((leaf_v >= nblk) | ~jnp.isfinite(next_lb)
                 | (next_lb.astype(jnp.float32) ** 2 >= kth2))
         return ((ad2, asid, aoff), ast, cert, leaf_v, comb_idx, visited,

@@ -137,33 +137,57 @@ def build_envelopes_znorm(series: jnp.ndarray, p: EnvelopeParams):
     return lo, hi, jnp.sum(master_ok, axis=1).astype(jnp.int32)
 
 
+# Series per envelope-build program.  The Z-normalized builder's scan
+# over subsequence lengths keeps (series, n_env, g, w) temporaries live,
+# so one program over a million series of 256 needs ~26 GB of device
+# memory; blocks of 2^15 series need under 1 GB.  The builder is
+# per-series, so a blocked build is bit-identical to an unblocked one.
+_BUILD_BLOCK = 1 << 15
+
+
 @partial(jax.jit, static_argnames=("p",))
+def _build_block(data: jnp.ndarray, p: EnvelopeParams,
+                 breakpoints: jnp.ndarray):
+    builder = build_envelopes_znorm if p.znorm else build_envelopes_raw
+    lo, hi, n_master = jax.vmap(builder, in_axes=(0, None))(data, p)
+    return (lo, hi, n_master, isax.symbolize(lo, breakpoints),
+            isax.symbolize(hi, breakpoints))
+
+
 def build_envelope_set(collection: Collection, p: EnvelopeParams,
                        breakpoints: jnp.ndarray) -> EnvelopeSet:
     """Build the full (unsorted) EnvelopeSet of a collection (paper Alg. 3).
 
-    vmaps the per-series builder over the stacked collection, then flattens
-    to a struct-of-arrays EnvelopeSet and symbolizes the bounds with iSAX.
+    vmaps the per-series builder over blocks of `_BUILD_BLOCK` stacked
+    series (the last block zero-padded, so every block reuses one
+    program), then flattens to a struct-of-arrays EnvelopeSet with the
+    bounds symbolized by iSAX.
     """
     n = collection.series_len
     n_env = p.num_envelopes(n)
     if n_env == 0:
         raise ValueError(f"series_len={n} shorter than lmin={p.lmin}")
 
-    builder = build_envelopes_znorm if p.znorm else build_envelopes_raw
-    lo, hi, n_master = jax.vmap(builder, in_axes=(0, None))(collection.data, p)
+    data = collection.data
     S = collection.num_series
+    parts = []
+    for s0 in range(0, S, _BUILD_BLOCK):
+        blk = data[s0:s0 + _BUILD_BLOCK]
+        if S > _BUILD_BLOCK and blk.shape[0] < _BUILD_BLOCK:
+            blk = jnp.pad(blk, ((0, _BUILD_BLOCK - blk.shape[0]), (0, 0)))
+        parts.append(_build_block(blk, p, breakpoints))
+    lo, hi, n_master, sym_lo, sym_hi = (
+        jnp.concatenate(x)[:S] if len(parts) > 1 else x[0]
+        for x in zip(*parts))
 
-    lo = lo.reshape(S * n_env, p.w)
-    hi = hi.reshape(S * n_env, p.w)
-    n_master = n_master.reshape(S * n_env)
     series_id = jnp.repeat(jnp.arange(S, dtype=jnp.int32), n_env)
     anchor = jnp.tile(_anchors(n, p), S)
-
-    sym_lo = isax.symbolize(lo, breakpoints)
-    sym_hi = isax.symbolize(hi, breakpoints)
+    n_master = n_master.reshape(S * n_env)
     return EnvelopeSet(
-        paa_lo=lo, paa_hi=hi, sym_lo=sym_lo, sym_hi=sym_hi,
+        paa_lo=lo.reshape(S * n_env, p.w),
+        paa_hi=hi.reshape(S * n_env, p.w),
+        sym_lo=sym_lo.reshape(S * n_env, p.w),
+        sym_hi=sym_hi.reshape(S * n_env, p.w),
         series_id=series_id, anchor=anchor, n_master=n_master,
         valid=n_master > 0,
     )

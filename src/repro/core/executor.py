@@ -179,7 +179,9 @@ def ed_batch(windows: jnp.ndarray, q: jnp.ndarray, znorm: bool):
     and ED^2 = 2l - 2 (W @ q) / sigma_w.
     """
     l = windows.shape[-1]
-    dots = windows @ q  # (M,)
+    # HIGHEST: f32 passes on the TPU's MXU, not its default single
+    # bf16 pass (whose error would swamp near-tie distances)
+    dots = jnp.dot(windows, q, precision=jax.lax.Precision.HIGHEST)
     if znorm:
         mu = jnp.mean(windows, axis=-1)
         var = jnp.mean(windows * windows, axis=-1) - mu * mu
@@ -446,10 +448,11 @@ def _survivor_bucket(data, qs, cand_sid, cand_off, sidx, mu, sd, j,
         axis=1)                                          # (B, sb)
     bs = jnp.take_along_axis(cand_sid, bi, axis=1)
     bo = jnp.take_along_axis(cand_off, bi, axis=1)
-    flat = (bs[:, :, None] * n
-            + jnp.clip(bo, 0, n - qlen)[:, :, None]
-            + jnp.arange(qlen, dtype=jnp.int32))
-    wb = jnp.take(data.reshape(-1), flat, mode="clip")
+    # one (1, qlen) row slice per candidate: a flat take would make
+    # XLA relayout the whole (8, 128)-tiled collection first
+    wb = jax.vmap(jax.vmap(
+        lambda s, o: jax.lax.dynamic_slice(data, (s, o), (1, qlen))[0]))(
+            bs, jnp.clip(bo, 0, n - qlen))
     if znorm:
         # normalize EXACTLY as the LB tier did (kernel mu/sd) so
         # LB_Keogh <= DTW holds bitwise on survivors
@@ -475,13 +478,20 @@ def _pool_merge(pool, cd2, csid, coff, k: int):
             jnp.take_along_axis(allo, sel, axis=1))
 
 
-def _first_lb2(lbs2, i, chunk: int):
-    """The (B,) squared lower bound heading chunk i of the packed plan —
-    the LB-sorted order makes it the chunk's (and every later chunk's)
-    best case, so it alone decides the scan's stop/skip tests."""
-    n_pad = lbs2.shape[1]
+def _chunk_heads(lbs2, chunk: int):
+    """(B, n_chunks) squared lower bounds heading each chunk of the
+    packed plan — the LB-sorted order makes each one its chunk's (and
+    every later chunk's) best case, so it alone decides the scan's
+    stop/skip tests.  Taken once, outside the scan loop: slicing a
+    (B, 1) column out of the (B, n_pad) plan every trip makes XLA lay
+    the whole plan out column-major, padding B to 128 lanes."""
+    return lbs2[:, ::chunk]
+
+
+def _first_lb2(heads, i):
+    """Chunk i's head from `_chunk_heads`; callers mask i >= n_chunks."""
     return jax.lax.dynamic_slice_in_dim(
-        lbs2, jnp.minimum(i * chunk, n_pad - 1), 1, axis=1)[:, 0]
+        heads, jnp.minimum(i, heads.shape[1] - 1), 1, axis=1)[:, 0]
 
 
 def _scan_chunk_step(data, csum, csum2, cslo, cs2lo, center, sids,
@@ -597,8 +607,10 @@ def _device_scan_core(data, csum, csum2, cslo, cs2lo, center, sids,
     n_pad = sids.shape[1]
     n_chunks = n_pad // chunk
 
+    heads = _chunk_heads(lbs2, chunk)
+
     def active_at(i, pool):
-        first = _first_lb2(lbs2, i, chunk)
+        first = _first_lb2(heads, i)
         return ((i < n_chunks) & jnp.isfinite(first)
                 & (first < pool[0][:, k - 1]))
 
@@ -705,9 +717,10 @@ def _device_range_core(data, csum, csum2, cslo, cs2lo, center, sids,
     no_ovf = jnp.int32(n_chunks)
     rows_idx = jnp.arange(b_sz)[:, None]
 
+    heads = _chunk_heads(lbs2, chunk)
+
     def active_at(i, ovf):
-        first = jax.lax.dynamic_slice_in_dim(
-            lbs2, jnp.minimum(i * chunk, n_pad - 1), 1, axis=1)[:, 0]
+        first = _first_lb2(heads, i)
         return ((i < n_chunks) & jnp.isfinite(first)
                 & (first <= eps2) & (ovf == no_ovf))
 

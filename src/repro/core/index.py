@@ -140,10 +140,15 @@ def _pad_envelopes(env: EnvelopeSet, multiple: int) -> EnvelopeSet:
 
 
 def _sort_envelopes(env: EnvelopeSet) -> EnvelopeSet:
-    # push padding/invalid rows to the end, then lexicographic by iSAX(L)
-    order = isax.argsort_by_isax(
-        jnp.concatenate([(~env.valid[:, None]).astype(env.sym_lo.dtype),
-                         env.sym_lo], axis=1))
+    """Padding/invalid rows last, then lexicographic by iSAX(L).
+
+    The order is computed on the host: it runs once per build, an
+    XLA:TPU sort over more than ~16k rows takes over a minute to compile
+    (compiling for a v5e), and numpy's stable lexsort of millions of
+    rows takes seconds."""
+    words = np.concatenate([~np.asarray(env.valid)[:, None],
+                            np.asarray(env.sym_lo)], axis=1)
+    order = jnp.asarray(isax.argsort_by_isax(words))
     return jax.tree_util.tree_map(lambda x: jnp.take(x, order, axis=0), env)
 
 

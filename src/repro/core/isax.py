@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.scipy.special import ndtri
 
 
@@ -66,14 +67,23 @@ def pack_sort_key(sym_lo: jnp.ndarray, bits_per_symbol: int = 8) -> jnp.ndarray:
     return key
 
 
-def argsort_by_isax(sym_lo: jnp.ndarray) -> jnp.ndarray:
+def argsort_by_isax(sym_lo) -> np.ndarray:
     """Stable lexicographic argsort of envelopes by their iSAX(L) word.
 
     The ULISSE tree accommodates envelopes by iSAX(L) (paper §5.3); the
     TPU-native index replaces pointer chasing with a *sorted* envelope array
-    plus a dense block hierarchy, so locality only needs this sort.  Uses
-    lexsort over symbol columns (last key = most significant => pass column 0
-    last).
+    plus a dense block hierarchy, so locality only needs this sort.  A
+    host-side (numpy) sort: symbols are < 256 (EnvelopeParams caps card
+    at 256), so four consecutive columns pack into one uint32 key, most
+    significant first, with the same order — a 16-symbol word is a
+    4-key lexsort.
     """
-    keys = tuple(sym_lo[..., i] for i in range(sym_lo.shape[-1] - 1, -1, -1))
-    return jnp.lexsort(keys)
+    sym_lo = np.asarray(sym_lo).astype(np.uint32)
+    cols = sym_lo.shape[-1]
+    sym_lo = np.pad(sym_lo, [(0, 0)] * (sym_lo.ndim - 1)
+                    + [(0, -cols % 4)])
+    keys = [(sym_lo[..., c] << 24) | (sym_lo[..., c + 1] << 16)
+            | (sym_lo[..., c + 2] << 8) | sym_lo[..., c + 3]
+            for c in range(0, sym_lo.shape[-1], 4)]
+    # lexsort: the last key is primary
+    return np.lexsort(tuple(reversed(keys)))

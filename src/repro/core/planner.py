@@ -240,7 +240,8 @@ def device_leaf_pack(env_sid, env_anchor, env_nm, env_valid, blk_lb,
     core's per-chunk stop IS Alg. 4's "next leaf cannot improve" stop.
 
     Returns (sids, anchors, n_master, lbs2, comb_idx, blk_lb_sorted):
-    all (B, n_pad) except blk_lb_sorted (B, Nb); comb_idx maps each
+    all (B, n_pad) except blk_lb_sorted, the ascending (B, min(n_leaves
+    + 1, Nb)) best block bounds; comb_idx maps each
     packed row back to its combined-set envelope index (N for padding —
     scatter-dropped by device_scan_pack's exclusion).
     """
@@ -249,8 +250,11 @@ def device_leaf_pack(env_sid, env_anchor, env_nm, env_valid, blk_lb,
     n_delta = n_comb - n_main
     nd_pad = -(-n_delta // chunk) * chunk
 
-    order = jnp.argsort(blk_lb, axis=1)                     # (B, Nb)
-    blk_sorted = jnp.take_along_axis(blk_lb, order, axis=1)
+    # only the n_leaves best blocks (and the next one, for the exactness
+    # certificate) are ever read: a top-k, not a full per-query sort —
+    # ties resolve to the lower block index, as a stable argsort would
+    neg, order = jax.lax.top_k(-blk_lb, min(n_leaves + 1, nblk))
+    blk_sorted = -neg                                   # (B, <= n_leaves+1)
     leaf_lb2 = (blk_sorted[:, :n_leaves] ** 2).astype(jnp.float32)
 
     member = jnp.arange(chunk, dtype=jnp.int32)

@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import shard_map
 
 
 # --------------------------------------------------------------------------
@@ -149,8 +148,8 @@ def make_compressed_grad_transform(mesh, axes=("data",)):
             return jax.tree_util.tree_unflatten(tree, out)
 
         specs = jax.tree_util.tree_map(lambda _: P(), grads)
-        return shard_map(local, mesh=mesh, in_specs=(specs,),
-                         out_specs=specs)(grads)
+        return jax.shard_map(local, mesh=mesh, in_specs=(specs,),
+                             out_specs=specs)(grads)
 
     return transform
 

@@ -27,6 +27,8 @@ benchmark baseline the pruned sharded scan is measured against
 """
 from __future__ import annotations
 
+from concurrent.futures import ThreadPoolExecutor
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -36,7 +38,6 @@ from repro.core import bounds, executor, planner
 from repro.core.envelope import build_envelope_set
 from repro.core.types import Collection, EnvelopeParams, EnvelopeSet
 from repro.distributed import collectives
-from repro.distributed.compat import shard_map
 
 
 def shard_collection(mesh, data: jnp.ndarray, axes=("data",)):
@@ -116,8 +117,8 @@ def build_host_index(p: EnvelopeParams, breakpoints, data) -> dict:
 
 def build_sharded_index(mesh, p: EnvelopeParams, breakpoints, data,
                         axes=("data",), data_sharded=None):
-    """Build the collection + envelope arrays ONCE on host and lay both
-    out row-sharded over the mesh.
+    """Build the collection + envelope arrays once, each shard's rows on
+    its own device, laid out row-sharded over the mesh.
 
     The PR-1 path rebuilt every shard's envelopes in-graph on every
     query; here the summarization runs once at engine construction —
@@ -127,31 +128,52 @@ def build_sharded_index(mesh, p: EnvelopeParams, breakpoints, data,
     a local build over the same series.  `build_envelope_set` flattens
     per series (rows [s*n_env, (s+1)*n_env) belong to series s), so a
     series-divisible mesh shards the envelope rows evenly with plain
-    row sharding — no padding, no re-grouping.
+    row sharding — no padding, no re-grouping.  Each device summarizes
+    only its own rows (series ids offset to global), so no device ever
+    holds more than its shard: a collection sized to fill every chip
+    never transits one.
 
     Returns a dict of sharded jax.Arrays keyed by SHARDED_INDEX_FIELDS;
     `data_sharded` (if given) is reused as the "data" entry so the raw
     series are not duplicated on device.
     """
-    coll = Collection.from_array(np.asarray(data, np.float32))
-    env = build_envelope_set(coll, p, breakpoints)
-    spec = P(axes if len(axes) > 1 else axes[0])
+    data = np.asarray(data, np.float32)
+    sharding = NamedSharding(mesh, P(axes if len(axes) > 1 else axes[0]))
+    n_env = p.num_envelopes(data.shape[1])
+    placement = list(sharding.addressable_devices_indices_map(
+        data.shape).items())
 
-    def put(x):
-        return jax.device_put(x, NamedSharding(mesh, spec))
+    def build(dev, rows):
+        with jax.default_device(dev):
+            coll = Collection.from_array(data[rows])
+            env = build_envelope_set(coll, p,
+                                     jax.device_put(breakpoints, dev))
+            env.series_id = env.series_id + (rows.start or 0)
+        return [jax.device_put(
+            getattr(env if f in _ENV_FIELDS else coll, f), dev)
+            for f in SHARDED_INDEX_FIELDS]
 
-    out = {
-        "data": data_sharded if data_sharded is not None
-        else put(coll.data),
-        "csum": put(coll.csum), "csum2": put(coll.csum2),
-        "csum_lo": put(coll.csum_lo), "csum2_lo": put(coll.csum2_lo),
-        "center": put(coll.center),
-        "paa_lo": put(env.paa_lo), "paa_hi": put(env.paa_hi),
-        "sym_lo": put(env.sym_lo), "sym_hi": put(env.sym_hi),
-        "series_id": put(env.series_id), "anchor": put(env.anchor),
-        "n_master": put(env.n_master), "valid": put(env.valid),
-    }
-    return out
+    # the host prefix sums dominate and release the GIL: one thread per
+    # device overlaps them across shards
+    with ThreadPoolExecutor(len(placement)) as ex:
+        built = list(ex.map(lambda di: build(di[0], di[1][0]), placement))
+    pieces = {f: [b[i] for b in built]
+              for i, f in enumerate(SHARDED_INDEX_FIELDS)}
+
+    def assemble(f):
+        shard = pieces[f][0]
+        rows = data.shape[0] * (n_env if f in _ENV_FIELDS else 1)
+        return jax.make_array_from_single_device_arrays(
+            (rows,) + shard.shape[1:], sharding, pieces[f])
+
+    return {f: data_sharded if f == "data" and data_sharded is not None
+            else assemble(f) for f in SHARDED_INDEX_FIELDS}
+
+
+# SHARDED_INDEX_FIELDS taken from the EnvelopeSet (the rest are
+# Collection fields)
+_ENV_FIELDS = ("paa_lo", "paa_hi", "sym_lo", "sym_hi", "series_id",
+               "anchor", "n_master", "valid")
 
 
 def _shard_row_index(mesh, axes):
@@ -202,9 +224,11 @@ def _sharded_knn_scan(coll: Collection, sids, anchors, n_master, lbs2,
     budget = (min(budget_chunks + delta_chunks, n_chunks)
               if budget_chunks else n_chunks)
 
+    heads = executor._chunk_heads(lbs2, chunk)
+
     def local_active(i, pool, gkth):
         kth = jnp.minimum(pool[0][:, k - 1], gkth)
-        f = executor._first_lb2(lbs2, i, chunk)
+        f = executor._first_lb2(heads, i)
         return (i < budget) & jnp.isfinite(f) & (f < kth)
 
     def chunk_step(j, carry):
@@ -248,7 +272,7 @@ def _sharded_knn_scan(coll: Collection, sids, anchors, n_master, lbs2,
     # covers every earlier per-query stop too
     gkth = collectives.global_kth(pool[0], k, axis_name)
     kth = jnp.minimum(pool[0][:, k - 1], gkth)
-    f = executor._first_lb2(lbs2, jnp.int32(budget), chunk)
+    f = executor._first_lb2(heads, jnp.int32(budget))
     rem = (budget < n_chunks) & jnp.isfinite(f) & (f < kth)
     cert = jax.lax.pmax(rem.astype(jnp.int32), axis_name) == 0
     return pool, stats, cert
@@ -360,11 +384,11 @@ def make_sharded_knn_query(mesh, p: EnvelopeParams, breakpoints, *,
         return md2, msid, moff, stats[None], cert
 
     spec_data = P(axes if len(axes) > 1 else axes[0])
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=tuple([spec_data] * (15 if with_gmap else 14)
                        + [P()] * 5),
-        out_specs=(P(), P(), P(), spec_data, P()), check=False)
+        out_specs=(P(), P(), P(), spec_data, P()), check_vma=False)
     return jax.jit(fn)
 
 
@@ -441,13 +465,13 @@ def make_sharded_range_query(mesh, p: EnvelopeParams, breakpoints, *,
 
     spec_data = P(axes if len(axes) > 1 else axes[0])
     row0 = axes if len(axes) > 1 else axes[0]
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=tuple([spec_data] * (15 if with_gmap else 14)
                        + [P()] * 6),
         out_specs=(P(None, row0), P(None, row0), P(None, row0),
                    spec_data, spec_data, spec_data, spec_data,
-                   spec_data, spec_data, spec_data), check=False)
+                   spec_data, spec_data, spec_data), check_vma=False)
     return jax.jit(fn), chunk
 
 
@@ -538,9 +562,9 @@ def make_batched_distributed_query(mesh, p: EnvelopeParams, breakpoints,
         return merged_d, merged_c, exact
 
     spec_data = P(axes if len(axes) > 1 else axes[0])
-    fn = shard_map(local_search, mesh=mesh,
-                   in_specs=(spec_data, P(), P()),
-                   out_specs=(P(), P(), P()), check=False)
+    fn = jax.shard_map(local_search, mesh=mesh,
+                       in_specs=(spec_data, P(), P()),
+                       out_specs=(P(), P(), P()), check_vma=False)
     return jax.jit(fn)
 
 

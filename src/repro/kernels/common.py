@@ -67,5 +67,14 @@ def cummin_lanes(x: jnp.ndarray, big: float = 1e30) -> jnp.ndarray:
 
 
 def default_interpret() -> bool:
-    """Run Pallas in interpret mode unless we are actually on TPU."""
-    return jax.default_backend() != "tpu"
+    """Pallas interpret mode on the CPU, Mosaic on the TPU.
+
+    Any other backend raises: interpreting there would hide that the
+    kernels never ran as device code.
+    """
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels run compiled on 'tpu' and interpreted on "
+            f"'cpu'; the default backend is {backend!r}")
+    return backend == "cpu"

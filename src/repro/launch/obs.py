@@ -40,7 +40,6 @@ def main(argv=None):
                          "scopes so spans align with XLA profiles")
     args = ap.parse_args(argv)
 
-    # BEFORE any jax import: stage (or verify) the device count
     _ensure_device_count(args.devices)
     import numpy as np
     import jax

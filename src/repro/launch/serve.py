@@ -19,36 +19,35 @@ import time
 
 
 def _ensure_device_count(n: int) -> None:
-    """Pin the host-platform device count BEFORE jax backend init.
+    """Serve on `n` virtual CPU devices, or refuse.
 
-    XLA reads XLA_FLAGS when the backend initializes, so the flag must
-    be staged before anything triggers that — and if some other module
-    in this process already initialized the backend, mutating
-    os.environ is silently dead.  In that case verify the device count
-    and fail loudly instead of serving on the wrong mesh.
+    `--xla_force_host_platform_device_count` is read when the backend
+    initializes and only the CPU backend obeys it, so the flag is staged
+    before that and the result verified after: on a TPU (or any other
+    backend), or once an earlier import has started the backend with a
+    different count, this raises instead of serving on the wrong mesh.
     """
     if not n:
         return
-    xb = sys.modules.get("jax._src.xla_bridge")
-    fn = getattr(xb, "backends_are_initialized", None) if xb else None
-    initialized = bool(fn() if fn is not None
-                       else getattr(xb, "_backends", {}) if xb else {})
-    if initialized:
-        import jax
-        if jax.device_count() != n:
-            raise RuntimeError(
-                f"--devices {n} requested but the jax backend is "
-                f"already initialized with {jax.device_count()} "
-                "device(s); XLA_FLAGS set now would be silently "
-                "ignored.  Set XLA_FLAGS="
-                f"--xla_force_host_platform_device_count={n} before "
-                "the first jax import (or drop --devices to serve on "
-                "the existing backend).")
-        return
-    flag = f"--xla_force_host_platform_device_count={n}"
-    prev = [f for f in os.environ.get("XLA_FLAGS", "").split()
-            if not f.startswith("--xla_force_host_platform_device_count")]
-    os.environ["XLA_FLAGS"] = " ".join(prev + [flag])
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        flag = f"--xla_force_host_platform_device_count={n}"
+        prev = [f for f in os.environ.get("XLA_FLAGS", "").split()
+                if not f.startswith(
+                    "--xla_force_host_platform_device_count")]
+        os.environ["XLA_FLAGS"] = " ".join(prev + [flag])
+    import jax
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"--devices {n} makes virtual CPU devices, but the backend "
+            f"is {jax.default_backend()!r}; drop --devices to serve on "
+            f"its {jax.device_count()} device(s)")
+    if jax.device_count() != n:
+        raise RuntimeError(
+            f"--devices {n} requested but the jax backend is already "
+            f"initialized with {jax.device_count()} device(s).  Set "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count={n} "
+            "before the first jax import (or drop --devices).")
 
 
 def main(argv=None):
@@ -71,7 +70,6 @@ def main(argv=None):
                          "dispatch")
     args = ap.parse_args(argv)
 
-    # BEFORE any jax import: stage (or verify) the device count
     _ensure_device_count(args.devices)
     import threading
 
@@ -79,8 +77,11 @@ def main(argv=None):
     import jax
 
     from repro.core import EnvelopeParams, QuerySpec, UlisseEngine
+    from repro.launch import configure_compile_cache
     from repro.serve import ServeConfig, UlisseServer
     from repro.train.data import series_batches
+
+    configure_compile_cache()
 
     n_dev = jax.device_count()
     ns = (args.series // n_dev) * n_dev

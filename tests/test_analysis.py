@@ -34,8 +34,6 @@ def _while_under_shard_map(step_fn):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.compat import shard_map
-
     d = jax.device_count()
     mesh = jax.make_mesh((d,), ("x",))
 
@@ -50,8 +48,8 @@ def _while_under_shard_map(step_fn):
 
         return jax.lax.while_loop(cond, step, (0, x))[1]
 
-    f = shard_map(local, mesh=mesh, in_specs=(P("x"),),
-                  out_specs=P("x"), check=False)
+    f = jax.shard_map(local, mesh=mesh, in_specs=(P("x"),),
+                      out_specs=P("x"), check_vma=False)
     return jax.make_jaxpr(f)(jnp.ones((d * 8,), jnp.float32))
 
 
@@ -127,11 +125,10 @@ def test_r1_real_scan_cores_audit_clean():
 def test_r3_flags_tainted_downcast_and_spares_untainted():
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.analysis.jaxpr_walk import f64_downcasts
 
-    with enable_x64():
+    with jax.enable_x64(True):
         def bad(hi, lo):
             return ((hi + lo).astype(jnp.float32) * 2.0)
 

@@ -11,9 +11,11 @@ import textwrap
 
 import pytest
 
-ENV = dict(os.environ,
+# virtual devices are a CPU-backend knob: the children never touch a chip
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ENV = dict(os.environ, JAX_PLATFORMS="cpu",
            XLA_FLAGS="--xla_force_host_platform_device_count=8",
-           PYTHONPATH="/root/repo/src:/root/repo")
+           PYTHONPATH=os.pathsep.join([os.path.join(_ROOT, "src"), _ROOT]))
 
 
 def run_sub(code: str):
@@ -132,16 +134,15 @@ def test_topk_merge_and_bsf():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.distributed.collectives import topk_merge, bsf_allreduce
-        from repro.distributed.compat import shard_map
         mesh = jax.make_mesh((8,), ("x",))
         def local(d, i):
             md, mi = topk_merge(d, i, 3, "x")
             return md, mi, bsf_allreduce(jnp.min(d), "x")
         d = jnp.arange(24, dtype=jnp.float32)[::-1].reshape(8, 3) / 10
         i = jnp.arange(24, dtype=jnp.int32).reshape(8, 3)
-        f = shard_map(local, mesh=mesh,
-                      in_specs=(P("x"), P("x")),
-                      out_specs=(P(), P(), P()), check=False)
+        f = jax.shard_map(local, mesh=mesh,
+                          in_specs=(P("x"), P("x")),
+                          out_specs=(P(), P(), P()), check_vma=False)
         md, mi, bsf = f(d.reshape(24), i.reshape(24))
         np.testing.assert_allclose(np.asarray(md), [0.0, 0.1, 0.2])
         assert float(bsf) == 0.0
@@ -154,15 +155,14 @@ def test_ef_int8_allreduce_error_feedback():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.distributed.collectives import ef_int8_allreduce
-        from repro.distributed.compat import shard_map
         mesh = jax.make_mesh((8,), ("x",))
         rng = np.random.default_rng(0)
         x = jnp.asarray(rng.normal(size=(8, 64)), jnp.float32)
         def local(xs):
             red, err = ef_int8_allreduce(xs[0], jnp.zeros_like(xs[0]), "x")
             return red[None], err[None]
-        f = shard_map(local, mesh=mesh, in_specs=(P("x"),),
-                      out_specs=(P("x"), P("x")), check=False)
+        f = jax.shard_map(local, mesh=mesh, in_specs=(P("x"),),
+                          out_specs=(P("x"), P("x")), check_vma=False)
         red, err = f(x)
         exact = np.mean(np.asarray(x), axis=0)
         got = np.asarray(red)[0]
@@ -178,15 +178,14 @@ def test_ring_allgather_matmul():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.distributed.collectives import ring_allgather_matmul
-        from repro.distributed.compat import shard_map
         mesh = jax.make_mesh((8,), ("x",))
         rng = np.random.default_rng(1)
         x = jnp.asarray(rng.normal(size=(32, 16)), jnp.float32)
         w = jnp.asarray(rng.normal(size=(16, 8)), jnp.float32)
         def local(xs, w):
             return ring_allgather_matmul(xs, w, "x", 8)[None]
-        f = shard_map(local, mesh=mesh, in_specs=(P("x"), P()),
-                      out_specs=P("x"), check=False)
+        f = jax.shard_map(local, mesh=mesh, in_specs=(P("x"), P()),
+                          out_specs=P("x"), check_vma=False)
         y = np.asarray(f(x, w))[0]
         np.testing.assert_allclose(y, np.asarray(x) @ np.asarray(w),
                                    rtol=1e-4, atol=1e-4)

@@ -23,9 +23,11 @@ import subprocess
 import sys
 import textwrap
 
-ENV = dict(os.environ,
+# virtual devices are a CPU-backend knob: the children never touch a chip
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ENV = dict(os.environ, JAX_PLATFORMS="cpu",
            XLA_FLAGS="--xla_force_host_platform_device_count=4",
-           PYTHONPATH="/root/repo/src:/root/repo")
+           PYTHONPATH=os.pathsep.join([os.path.join(_ROOT, "src"), _ROOT]))
 
 
 def run_sub(code: str):
