@@ -40,7 +40,7 @@ from repro.core import executor, planner
 from repro.core.executor import SearchResult, SearchStats, TopK
 from repro.core.index import UlisseIndex, build_index
 from repro.core.types import Collection, EnvelopeParams
-from repro.obs import span
+from repro.obs import device_stage, span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1213,8 +1213,9 @@ class UlisseEngine:
         block_size = n_main // nblk
         chunk = executor.pow2ceil(block_size)
         n_leaves = min(spec.max_leaves, nblk)
-        b = qstack.shape[0]
+        b, qlen = qstack.shape
 
+        mark = device_stage("device.approx_plan", qlen=qlen, batch=b)
         blk_lb = planner.block_lower_bounds_batch(
             qb, qh, fine.paa_lo, fine.paa_hi, fine.valid, p.seg_len,
             nseg)
@@ -1223,6 +1224,8 @@ class UlisseEngine:
             env.series_id, env.anchor, env.n_master, env.valid, blk_lb,
             n_main=n_main, block_size=block_size, chunk=chunk,
             n_leaves=n_leaves)
+        mark.ready(blk_sorted)
+        mark = device_stage("device.approx_scan", qlen=qlen, batch=b)
         neg = jnp.full((b, k), -1, jnp.int32)
         seed = (jnp.full((b, k), jnp.inf, jnp.float32), neg, neg)
         store = self._paged_store()
@@ -1253,6 +1256,7 @@ class UlisseEngine:
                              jnp.minimum(leaf_v, blk_sorted.shape[1] - 1)]
         cert = ((leaf_v >= nblk) | ~jnp.isfinite(next_lb)
                 | (next_lb.astype(jnp.float32) ** 2 >= kth2))
+        mark.ready(cert)
         return ((ad2, asid, aoff), ast, cert, leaf_v, comb_idx, visited,
                 chunk, nblk, asids.shape[1] // chunk)
 
@@ -1365,6 +1369,8 @@ class UlisseEngine:
                         visited = jnp.zeros((b,), jnp.int32)
                         achunk, nblk, aplan = 1, 0, 0
                     with span("pack"):
+                        mark = device_stage("device.plan", qlen=qlen,
+                                            batch=b)
                         lbs = planner.env_lower_bounds_batch(
                             qb, qh, env, index.breakpoints,
                             self.params.seg_len, nseg,
@@ -1375,7 +1381,10 @@ class UlisseEngine:
                             env.series_id, env.anchor, env.n_master,
                             lbs, comb_idx, visited, chunk=achunk,
                             n_pad=n_pad)
+                        mark.ready(slbs2)
                     with span("device_scan"):
+                        mark = device_stage("device.scan", qlen=qlen,
+                                            batch=b)
                         store = self._paged_store()
                         if store is None:
                             d2, sid, off, st = executor.device_exact_scan(
@@ -1396,6 +1405,7 @@ class UlisseEngine:
                                 measure=spec.measure, r=spec.r,
                                 znorm=self.params.znorm,
                                 chunk_size=spec.chunk_size)
+                        mark.ready(st)
                         # THE one host sync of the batch
                         (d2, sid, off, st, ast, cert,
                          leaf_v) = jax.device_get(
@@ -1429,7 +1439,13 @@ class UlisseEngine:
                             results[i] = self._knn_result_rows(
                                 qs[i], spec, d2[row], sid[row],
                                 off[row], stats)
-                    sp.set(chunks=int(st[:, 0].sum()))
+                    # loop trips of the batched scan: lanes run in
+                    # lockstep until the last one stops, and a lane
+                    # that stops stays stopped; padded lanes repeat
+                    # the last query and count as unused
+                    fill = len(sub)
+                    sp.set(chunks=int(st[:fill, 0].sum()),
+                           steps=int(st[:, 0].max()), lanes=b, fill=fill)
         return results
 
     def _local_approx_device(self, qs, spec: QuerySpec):

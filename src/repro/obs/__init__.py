@@ -3,9 +3,11 @@
 One substrate, three surfaces (DESIGN.md §12):
 
   * ``get_tracer()`` / ``span(...)`` — the process-wide sampling
-    `Tracer`.  Engine and server call ``span()`` unconditionally; it is
-    a near-free no-op until someone calls
-    ``get_tracer().configure(enabled=True)``.
+    `Tracer`.  Engine and server call ``span()``, ``wait_span()`` and
+    ``device_stage()`` unconditionally; each is a near-free no-op until
+    someone calls ``get_tracer().configure(enabled=True)``.  While it
+    is on, every compile JAX reports is recorded as a ``jax.compile``
+    span under the span open on the compiling thread.
   * ``get_registry()`` — the process-wide `MetricsRegistry` that
     `ServeMetrics` mirrors into and `record_search_stats` feeds, with
     Prometheus text / JSON snapshot exporters.
@@ -18,6 +20,10 @@ numbers become scrapeable.
 """
 from __future__ import annotations
 
+import time
+
+import jax.monitoring
+
 from .registry import DEFAULT_BUCKETS, MetricsRegistry
 from .tracer import Span, Tracer
 
@@ -26,6 +32,7 @@ __all__ = [
     "MetricsRegistry",
     "Span",
     "Tracer",
+    "device_stage",
     "get_registry",
     "get_tracer",
     "record_page_stats",
@@ -33,6 +40,7 @@ __all__ = [
     "set_registry",
     "set_tracer",
     "span",
+    "wait_span",
 ]
 
 _tracer = Tracer()
@@ -54,6 +62,37 @@ def set_tracer(tracer: Tracer) -> Tracer:
 def span(name: str, **attrs):
     """Open a span on the process-wide tracer — the one call sites use."""
     return _tracer.span(name, **attrs)
+
+
+def wait_span(name: str, **attrs):
+    """Open a wait span (outside the sampling draw) on the process-wide
+    tracer."""
+    return _tracer.wait_span(name, **attrs)
+
+
+def device_stage(name: str, **attrs):
+    """Mark the launch of a device stage on the process-wide tracer;
+    ``.ready(out)`` on the result ends it when `out` is ready."""
+    return _tracer.device_stage(name, **attrs)
+
+
+# JAX reports each phase of a compile (jaxpr trace, lowering to MLIR,
+# backend compile) as "/jax/core/compile/<phase>_duration" with the
+# function's name, on the compiling thread, as the phase ends
+_COMPILE_EVENT = "/jax/core/compile/"
+
+
+def _on_jax_duration(event: str, duration: float, **kw) -> None:
+    if not _tracer.enabled or not event.startswith(_COMPILE_EVENT):
+        return
+    t1 = time.perf_counter()
+    phase = event[len(_COMPILE_EVENT):].removesuffix("_duration")
+    _tracer.record_interval("jax.compile", t1 - duration, t1,
+                            fun_name=str(kw.get("fun_name", "")),
+                            phase=phase)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
 
 
 def get_registry() -> MetricsRegistry:

@@ -31,7 +31,11 @@ this module is what exploits it under load:
     engine pruning counters (`server.metrics_text()` = one Prometheus
     scrape for the whole pipeline).  With `repro.obs` tracing enabled,
     each request leaves admission -> queue_wait -> dispatch spans that
-    nest around the engine's prepare/pack/device-scan/merge spans.
+    nest around the engine's prepare/pack/device-scan/merge spans; all
+    three carry the request's id (`Ticket.req`: `req` on admission and
+    queue wait, `reqs` on the dispatch).  Every wait of the dispatcher
+    is a span too: `serve.idle` while no request is queued,
+    `serve.hold` while queued requests wait out the window.
 
 Typical use::
 
@@ -54,7 +58,7 @@ from typing import Deque, Dict, Optional, Sequence
 from repro import obs
 from repro.core import planner
 from repro.core.engine import QuerySpec, UlisseEngine
-from repro.obs import span
+from repro.obs import span, wait_span
 from repro.serve.metrics import ServeMetrics
 
 # -- thread-discipline declarations (repro.analysis rule T1) ---------------
@@ -110,6 +114,8 @@ THREAD_ATTRS = {
     "UlisseServer._buckets": ("client", "dispatcher"),
     "UlisseServer._writer": ("client", "dispatcher"),
     "UlisseServer._pending": ("client", "dispatcher"),
+    # the last request id handed out; written under the lock by submit
+    "UlisseServer._next_req": ("client",),
     # dispatcher-private: written between dispatches only; the version
     # property's unguarded int read is a snapshot, never torn
     "UlisseServer._version": ("dispatcher",),
@@ -151,16 +157,19 @@ class Ticket:
     """Completion handle for one admitted request or writer op.
 
     `snapshot` is the index version the work executed under (writer
-    ops bump it); set at dispatch, valid once `done()`.
+    ops bump it); set at dispatch, valid once `done()`.  `req` is the
+    request's id, which its spans carry (None for writer ops and
+    refused requests).
     """
 
-    __slots__ = ("bucket", "snapshot", "t_submit", "_event", "_value",
-                 "_error")
+    __slots__ = ("bucket", "snapshot", "t_submit", "req", "_event",
+                 "_value", "_error")
 
     def __init__(self, bucket: Optional[int] = None):
         self.bucket = bucket
         self.snapshot: Optional[int] = None
         self.t_submit = 0.0
+        self.req: Optional[int] = None
         self._event = threading.Event()
         self._value = None
         self._error: Optional[BaseException] = None
@@ -252,6 +261,7 @@ class UlisseServer:
         self._buckets: Dict[int, Deque[_Request]] = {}
         self._writer: Deque[_WriterOp] = deque()
         self._pending = 0
+        self._next_req = 0
         self._version = 0
         # adaptive hold window: starts at the configured value so the
         # first requests can still coalesce; drops to 0 once a dispatch
@@ -355,6 +365,9 @@ class UlisseServer:
                         max_pending=self.config.max_pending,
                         bucket=bucket)
                 ticket.t_submit = time.perf_counter()
+                self._next_req += 1
+                ticket.req = self._next_req
+                sp.set(req=ticket.req)
                 self._buckets.setdefault(bucket, deque()).append(
                     _Request(arr, ticket))
                 self._pending += 1
@@ -439,8 +452,12 @@ class UlisseServer:
                         break
                     if self._closed:
                         return       # drained (or flushed by close)
-                    self._cond.wait(self._timeout_locked(
-                        self._eff_window))
+                    # name the wait: nothing queued, or queued requests
+                    # waiting out the hold window
+                    with wait_span("serve.hold" if self._pending
+                                   else "serve.idle"):
+                        self._cond.wait(self._timeout_locked(
+                            self._eff_window))
             if op is not None:
                 self._apply_writer(op)
             else:
@@ -486,15 +503,15 @@ class UlisseServer:
     def _dispatch(self, bucket: int, batch) -> None:
         t0 = time.perf_counter()
         tracer = obs.get_tracer()
-        with span("serve.dispatch", bucket=bucket,
-                  fill=len(batch)) as sp:
+        with span("serve.dispatch", bucket=bucket, fill=len(batch),
+                  reqs=[r.ticket.req for r in batch]) as sp:
             # the waits happened across threads, before this span
             # opened: record them as externally-timed queue_wait spans
             # so a trace shows submit->dispatch next to the dispatch
             for r in batch:
                 tracer.record_interval("serve.queue_wait",
                                        r.ticket.t_submit, t0,
-                                       bucket=bucket)
+                                       bucket=bucket, req=r.ticket.req)
             self.metrics.record_dispatch(
                 bucket, fill=len(batch),
                 waits=[t0 - r.ticket.t_submit for r in batch])

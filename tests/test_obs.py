@@ -312,3 +312,234 @@ def test_quickstart_stats_surface():
         assert field in d
     assert 0.0 <= d["pruning_power"] <= 1.0
     assert d["chunks_planned"] >= d["chunks_visited"] >= 0
+
+
+# -------------------------------------------------------------------------
+# span identity, device stages, dispatcher waits, compiles
+# -------------------------------------------------------------------------
+
+def test_span_ids_and_parents_across_nesting():
+    """Every span gets a fresh id and its parent is the span open on
+    its thread when it started; an externally-timed interval takes the
+    span open where it is recorded."""
+    tr = Tracer(enabled=True)
+    with tr.span("root"):
+        with tr.span("child"):
+            with tr.span("leaf"):
+                pass
+            tr.record_interval("wait", 0.0, 0.0, req=7)
+    with tr.span("root2"):
+        pass
+    by = {s.name: s for s in tr.drain()}
+    ids = [s.id for s in by.values()]
+    assert len(set(ids)) == len(ids) and all(i > 0 for i in ids)
+    assert by["root"].parent is None and by["root2"].parent is None
+    assert by["child"].parent == by["root"].id
+    assert by["leaf"].parent == by["child"].id
+    assert by["wait"].parent == by["child"].id
+    d = by["leaf"].as_dict()
+    assert d["id"] == by["leaf"].id and d["parent"] == by["child"].id
+    for key in ("name", "t0", "dur", "tid", "depth", "attrs"):
+        assert key in d
+
+
+def test_ids_are_per_thread_parents():
+    """A span opened on another thread does not take this thread's open
+    span as its parent."""
+    import threading
+    tr = Tracer(enabled=True)
+    seen = {}
+
+    def other():
+        with tr.span("other") as sp:
+            seen["id"] = sp.id
+
+    with tr.span("main"):
+        t = threading.Thread(target=other)
+        t.start()
+        t.join()
+    by = {s.name: s for s in tr.drain()}
+    assert by["other"].parent is None
+    assert by["other"].id == seen["id"] != by["main"].id
+
+
+def test_device_mark_off_path_holds_nothing():
+    """Tracing off: span(), wait_span() and the device mark are all the
+    shared no-op; the mark starts no thread and keeps no reference to
+    the array it is handed."""
+    import gc
+    import threading
+    import weakref
+
+    import jax.numpy as jnp
+
+    def watchers():
+        return [t for t in threading.enumerate()
+                if t.name == "ulisse-obs-device"]
+
+    before = len(watchers())
+    tr = Tracer()
+    assert tr.span("x") is _NULL_SPAN
+    assert tr.wait_span("serve.idle") is _NULL_SPAN
+    mark = tr.device_stage("device.scan", qlen=8, batch=1)
+    assert mark is _NULL_SPAN
+    out = jnp.arange(5.0) * 2
+    ref = weakref.ref(out)
+    mark.ready(out)
+    del out
+    gc.collect()
+    assert ref() is None
+    assert len(watchers()) == before
+    assert len(tr) == 0
+
+
+def test_device_mark_in_unsampled_trace_is_null():
+    tr = Tracer(enabled=True, sample_every=2)
+    with tr.span("unsampled"):           # the draw keeps every 2nd root
+        assert tr.device_stage("device.scan") is _NULL_SPAN
+    with tr.span("sampled"):
+        pass
+    assert [s.name for s in tr.drain()] == ["sampled"]
+
+
+def test_device_stages_tile_in_launch_order():
+    """Stages recorded by the watcher: launch order, no overlap, each
+    from max(launch, previous ready) to its own ready, parented to the
+    span open at launch, all present once drain() returns."""
+    import jax.numpy as jnp
+    tr = Tracer(enabled=True)
+    x = jnp.ones((64, 64))
+    with tr.span("query") as q:
+        for name in ("device.a", "device.b", "device.c"):
+            mark = tr.device_stage(name, qlen=64, batch=1)
+            x = x @ x / 64.0
+            mark.ready(x)
+    spans = tr.drain()
+    dev = [s for s in spans if s.name.startswith("device.")]
+    assert [s.name for s in dev] == ["device.a", "device.b", "device.c"]
+    (root,) = [s for s in spans if s.name == "query"]
+    for a, b in zip(dev, dev[1:]):
+        assert b.t0 >= a.t0 + a.dur - 1e-9
+    for s in dev:
+        assert s.parent == q.id == root.id
+        assert s.attrs == {"qlen": 64, "batch": 1}
+        assert s.t0 >= root.t0 and s.dur >= 0
+
+
+def test_wait_spans_use_no_sampling_draw():
+    """wait_span records whenever tracing is on and leaves the 1-in-N
+    root draw to the traces it sits between."""
+    tr = Tracer(enabled=True, sample_every=2)
+    for _ in range(6):
+        with tr.wait_span("serve.idle"):
+            pass
+        with tr.span("root"):
+            pass
+    names = [s.name for s in tr.drain()]
+    assert names.count("serve.idle") == 6
+    assert names.count("root") == 3
+
+
+def test_wait_span_becomes_annotation_with_id(monkeypatch):
+    """With jax_annotations each live span enters a TraceAnnotation
+    named after it, carrying its id as a scalar stat."""
+    from repro.obs import tracer as tracer_mod
+    made = []
+
+    class Fake:
+        def __init__(self, name, **kw):
+            made.append((name, kw))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(tracer_mod, "TraceAnnotation", Fake)
+    tr = Tracer(enabled=True, jax_annotations=True)
+    with tr.wait_span("serve.idle") as w:
+        pass
+    with tr.span("serve.dispatch", reqs=[1, 2]) as d:
+        pass
+    assert made == [("serve.idle", {"id": w.id}),
+                    ("serve.dispatch", {"id": d.id})]
+
+
+def test_compile_recorded_under_traced_span():
+    """A new shape inside a traced span records jax.compile spans (one
+    per phase) parented to that span; with tracing off none record."""
+    import jax
+    import jax.numpy as jnp
+
+    prev = obs.set_tracer(Tracer())
+    try:
+        f = jax.jit(lambda x: x * 3.0 + 1.0)
+        f(jnp.ones((3, 5)))                  # tracer off: nothing
+        assert len(obs.get_tracer()) == 0
+        obs.get_tracer().configure(enabled=True)
+        with obs.span("outer") as sp:
+            f(jnp.ones((7, 11)))             # a shape not seen yet
+        spans = obs.get_tracer().drain()
+    finally:
+        obs.set_tracer(prev)
+    comp = [s for s in spans if s.name == "jax.compile"]
+    assert comp and all(s.parent == sp.id for s in comp)
+    phases = {s.attrs["phase"] for s in comp}
+    assert "backend_compile" in phases
+    assert any("lambda" in s.attrs["fun_name"] for s in comp)
+
+
+def test_chrome_trace_carries_ids_and_device_track():
+    import jax.numpy as jnp
+    tr = Tracer(enabled=True)
+    with tr.span("outer"):
+        mark = tr.device_stage("device.scan", qlen=4, batch=1)
+        mark.ready(jnp.zeros(4) + 1)
+    doc = json.loads(json.dumps(tr.chrome_trace()))
+    xs = {e["name"]: e for e in doc["traceEvents"] if e["ph"] == "X"}
+    outer, dev = xs["outer"], xs["device.scan"]
+    assert dev["args"]["parent"] == outer["args"]["id"]
+    assert outer["args"]["parent"] is None
+    assert dev["tid"] != outer["tid"]
+    (track,) = [e for e in doc["traceEvents"]
+                if e["ph"] == "M" and e["name"] == "thread_name"]
+    assert track["tid"] == dev["tid"] and track["args"]["name"] == "device"
+
+
+def test_device_marks_from_many_threads_all_record():
+    """Stress: more marking threads than cores, a short switch interval;
+    every stage handed to the watcher is recorded once drain() returns,
+    and ids stay unique."""
+    import os
+    import sys
+    import threading
+
+    import numpy as np
+
+    tr = Tracer(enabled=True, capacity=1 << 16)
+    n_threads = 2 * (os.cpu_count() or 2)
+    per = 25
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        def work(t):
+            with tr.span("root", t=t):
+                for i in range(per):
+                    tr.device_stage("device.x", i=i).ready(np.ones(3))
+
+        threads = [threading.Thread(target=work, args=(t,))
+                   for t in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+        spans = tr.drain()
+    finally:
+        sys.setswitchinterval(old)
+    dev = [s for s in spans if s.name == "device.x"]
+    assert len(dev) == n_threads * per
+    assert len({s.id for s in spans}) == len(spans)
+    roots = {s.id for s in spans if s.name == "root"}
+    assert all(s.parent in roots and s.dur >= 0 for s in dev)

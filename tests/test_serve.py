@@ -236,3 +236,91 @@ def test_adaptive_window_idle_fast_burst_batched(engine, walk_collection,
     max_fill = max(int(f) for row in snap["buckets"].values()
                    for f in row["fill_hist"])
     assert max_fill >= 2, f"burst never coalesced: {snap}"
+
+
+@pytest.fixture
+def traced():
+    """The process tracer switched to a fresh enabled one; restored."""
+    from repro import obs
+    prev = obs.set_tracer(obs.Tracer(enabled=True))
+    try:
+        yield obs.get_tracer()
+    finally:
+        obs.set_tracer(prev)
+
+
+def test_request_spans_share_its_id_and_name_waits(engine,
+                                                   walk_collection, rng,
+                                                   traced):
+    """One request's admission (client thread), queue wait and dispatch
+    (dispatcher thread) carry its id; the engine's spans and device
+    stages descend from the dispatch; the dispatcher's waits are named
+    serve.idle (nothing queued) and serve.hold (held for the window)."""
+    import sys
+    server = UlisseServer(engine, QuerySpec(k=3),
+                          ServeConfig(window_ms=300.0, max_batch=4))
+    qs = [walk_collection[i, 3:3 + 96]
+          + rng.normal(size=96).astype(np.float32) * 0.05
+          for i in range(3)]
+    tickets = [server.submit(q) for q in qs]
+    for t in tickets:
+        t.result(timeout=300)
+    server.close()
+    spans = traced.drain()
+    by_id = {s.id: s for s in spans}
+    named = lambda n: [s for s in spans if s.name == n]  # noqa: E731
+
+    reqs = [t.req for t in tickets]
+    assert len(set(reqs)) == 3 and None not in reqs
+    adm = {s.attrs["req"]: s for s in named("serve.admission")}
+    qw = {s.attrs["req"]: s for s in named("serve.queue_wait")}
+    (disp,) = named("serve.dispatch")
+    assert set(adm) == set(qw) == set(disp.attrs["reqs"]) == set(reqs)
+    for r in reqs:
+        assert adm[r].tid != disp.tid          # client vs dispatcher
+        assert qw[r].tid == disp.tid and qw[r].parent == disp.id
+
+    def ancestors(s):
+        while s.parent is not None:
+            s = by_id[s.parent]
+            yield s
+
+    (q,) = named("query.exact_device")
+    assert q.parent == disp.id
+    dev = [s for s in spans if s.name.startswith("device.")]
+    assert [s.name for s in dev] == ["device.approx_plan",
+                                     "device.approx_scan", "device.plan",
+                                     "device.scan"]
+    # the watcher reads its clock once it gets the interpreter back
+    late = 2 * sys.getswitchinterval() + 0.05
+    for s in dev:
+        assert disp in ancestors(s) and q in ancestors(s)
+        assert s.attrs == {"qlen": 96, "batch": 4}
+        assert s.t0 >= disp.t0
+        assert s.t0 + s.dur <= disp.t0 + disp.dur + late
+    for a, b in zip(dev, dev[1:]):
+        assert b.t0 >= a.t0 + a.dur - 1e-9
+
+    assert named("serve.idle") and named("serve.hold")
+    for s in named("serve.idle") + named("serve.hold"):
+        assert s.parent is None and s.tid == disp.tid
+    # the three requests were held together for the 300 ms window
+    assert sum(s.dur for s in named("serve.hold")) >= 0.2
+
+
+def test_exact_device_counts_scan_steps_on_padded_batch(engine,
+                                                        walk_collection,
+                                                        rng, traced):
+    """query.exact_device records the scan's loop trips (`steps`), its
+    lanes (the padded batch), the real rows (`fill`) and the chunks the
+    real rows visited — padding rows excluded."""
+    qs = [walk_collection[i, 7:7 + 96]
+          + rng.normal(size=96).astype(np.float32) * 0.05
+          for i in (0, 5, 11)]
+    res = engine.search(qs, QuerySpec(k=3, approx_first=False))
+    (q,) = [s for s in traced.drain() if s.name == "query.exact_device"]
+    visited = [r.stats.chunks_visited for r in res]
+    assert q.attrs["lanes"] == 4 and q.attrs["fill"] == 3
+    assert q.attrs["chunks"] == sum(visited) > 0
+    # the padding lane repeats the last query, so it stops with it
+    assert q.attrs["steps"] == max(visited)
