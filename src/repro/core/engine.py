@@ -738,9 +738,12 @@ class UlisseEngine:
         env = self._index.search_envelopes()
         out = {f: getattr(coll, f) for f in (
             "data", "csum", "csum2", "csum_lo", "csum2_lo", "center")}
+        out.update(paa_lo=env.paa_lo, paa_hi=env.paa_hi)
+        # the breakpoint intervals the plan reads, in the place of the
+        # iSAX symbols (those stay on the host)
+        out["interval_lo"], out["interval_hi"] = self._index.intervals
         out.update({f: getattr(env, f) for f in (
-            "paa_lo", "paa_hi", "sym_lo", "sym_hi", "series_id",
-            "anchor", "n_master", "valid")})
+            "series_id", "anchor", "n_master", "valid")})
         return out
 
     @property
@@ -1372,9 +1375,9 @@ class UlisseEngine:
                         mark = device_stage("device.plan", qlen=qlen,
                                             batch=b)
                         lbs = planner.env_lower_bounds_batch(
-                            qb, qh, env, index.breakpoints,
-                            self.params.seg_len, nseg,
-                            spec.use_paa_bounds)
+                            qb, qh, *index.lb_intervals(
+                                spec.use_paa_bounds),
+                            env.valid, self.params.seg_len, nseg)
                         n_pad = executor.pow2ceil(n_comb)
                         (ssids, sanc, snm, slbs2,
                          _) = planner.device_scan_pack(
@@ -1518,8 +1521,8 @@ class UlisseEngine:
                     queries, spec)
             with span("pack"):
                 lbs = planner.env_lower_bounds_batch(
-                    qb, qh, env, index.breakpoints, p.seg_len, nseg,
-                    spec.use_paa_bounds)
+                    qb, qh, *index.lb_intervals(spec.use_paa_bounds),
+                    env.valid, p.seg_len, nseg)
                 n_pad = executor.pow2ceil(n_comb)
                 (ssids, sanc, snm, slbs2,
                  order) = planner.device_range_pack(
@@ -1621,8 +1624,8 @@ class UlisseEngine:
         eps2 = float(spec.eps) ** 2
 
         lbs = np.asarray(planner.env_lower_bounds(
-            pq.paa_lo, pq.paa_hi, env, index.breakpoints,
-            self.params.seg_len, pq.nseg, spec.use_paa_bounds), np.float64)
+            pq.paa_lo, pq.paa_hi, *index.lb_intervals(spec.use_paa_bounds),
+            env.valid, self.params.seg_len, pq.nseg), np.float64)
         stats.lb_computations += env.size
         cand = np.nonzero((lbs ** 2) <= eps2)[0]
         stats.chunks_planned = -(-len(cand) // spec.chunk_size)

@@ -19,17 +19,18 @@ intervals, so mindist(block) <= mindist(member) (tested property).
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import isax
+from repro import obs
+from repro.core import bounds, isax
 from repro.core.envelope import build_envelope_set
 from repro.core.paa import paa
 from repro.core.types import (Collection, EnvelopeParams, EnvelopeSet,
-                              concat_envelope_sets)
+                              array_module, concat_envelope_sets)
 
 _NEG = jnp.float32(-jnp.inf)
 _POS = jnp.float32(jnp.inf)
@@ -66,6 +67,14 @@ class UlisseIndex:
     or `compact`.  The search layer treats main + delta as one candidate
     set (`search_envelopes`); the block hierarchy covers main only, so
     the approximate descent sweeps the (small) delta exhaustively.
+
+    The iSAX symbols of `envelopes` and `delta` live on the host (the
+    sort key, saves and compaction read them).  What the lower bounds
+    read of them, the breakpoint intervals [beta_l(iSAX(L)),
+    beta_u(iSAX(U))] of every row of `search_envelopes()`, is held on
+    the device as `intervals`, two f32 (N, w) arrays in the place the
+    symbols took.  They are computed once per index object — build,
+    `append` (a new object), `compact`, `open` — never per query.
     """
 
     envelopes: EnvelopeSet            # sorted by iSAX(L)
@@ -74,6 +83,16 @@ class UlisseIndex:
     breakpoints: jnp.ndarray          # (card-1,)
     params: EnvelopeParams = None     # static aux
     delta: Optional[EnvelopeSet] = None   # unsorted ingestion buffer
+    # (e_lo, e_hi) of search_envelopes(), set by __post_init__
+    intervals: Tuple[jnp.ndarray, jnp.ndarray] = dataclasses.field(
+        init=False, repr=False, default=None)
+
+    def __post_init__(self):
+        self.envelopes = host_symbols(self.envelopes)
+        if self.delta is not None:
+            self.delta = host_symbols(self.delta)
+        self.intervals = envelope_intervals(self.search_envelopes(),
+                                            self.breakpoints)
 
     def tree_flatten(self):
         return (self.envelopes, self.levels, self.collection,
@@ -114,6 +133,36 @@ class UlisseIndex:
             self._combined_cache = cached = (self.delta, combined)
         return cached[1]
 
+    def lb_intervals(self, use_paa: bool = False):
+        """The envelope side (e_lo, e_hi), each (N, w), of every lower
+        bound over `search_envelopes()`: the held breakpoint intervals
+        (paper Eq. 5 / Eq. 8), or the raw L/U PAA bounds when `use_paa`
+        (`QuerySpec.use_paa_bounds`)."""
+        if use_paa:
+            env = self.search_envelopes()
+            return env.paa_lo, env.paa_hi
+        return self.intervals
+
+
+SYMBOL_FIELDS = ("sym_lo", "sym_hi")
+
+
+def host_symbols(env: EnvelopeSet) -> EnvelopeSet:
+    """`env` with its iSAX symbols on the host (a no-op when they are)."""
+    return dataclasses.replace(env, **{
+        f: np.asarray(getattr(env, f)) for f in SYMBOL_FIELDS})
+
+
+def envelope_intervals(env: EnvelopeSet, breakpoints):
+    """The breakpoint intervals of `env` on the device, counted in
+    `ulisse_envelope_interval_builds_total`: once per build, append,
+    compact or open, and never per query."""
+    obs.get_registry().inc(
+        "ulisse_envelope_interval_builds_total",
+        help_text="Envelope breakpoint-interval builds (index build, "
+                  "append, compact, open)")
+    return bounds.envelope_breakpoint_bounds(env, breakpoints)
+
 
 # Padding-row fill per EnvelopeSet field.  +inf lo / -inf hi make
 # padding rows unreachable by every lower bound.  The storage Writer
@@ -132,7 +181,7 @@ def _pad_envelopes(env: EnvelopeSet, multiple: int) -> EnvelopeSet:
 
     def pad_arr(x, fill):
         cfg = [(0, pad)] + [(0, 0)] * (x.ndim - 1)
-        return jnp.pad(x, cfg, constant_values=fill)
+        return array_module(x).pad(x, cfg, constant_values=fill)
 
     return EnvelopeSet(**{
         field: pad_arr(getattr(env, field), fill)
@@ -148,8 +197,9 @@ def _sort_envelopes(env: EnvelopeSet) -> EnvelopeSet:
     rows takes seconds."""
     words = np.concatenate([~np.asarray(env.valid)[:, None],
                             np.asarray(env.sym_lo)], axis=1)
-    order = jnp.asarray(isax.argsort_by_isax(words))
-    return jax.tree_util.tree_map(lambda x: jnp.take(x, order, axis=0), env)
+    order = isax.argsort_by_isax(words)
+    return jax.tree_util.tree_map(
+        lambda x: array_module(x).take(x, order, axis=0), env)
 
 
 def _block_reduce(paa_lo, paa_hi, valid, block: int) -> BlockLevel:
@@ -203,7 +253,7 @@ def index_from_envelopes(env: EnvelopeSet, collection: Collection,
     equal iSAX keys stay in series order regardless of how the set was
     assembled (see repro/storage/delta.py).
     """
-    env = _sort_envelopes(env)
+    env = _sort_envelopes(host_symbols(env))
     env = _pad_envelopes(env, block_size ** max(num_levels, 1))
     levels = build_block_levels(env, block_size, num_levels)
     return UlisseIndex(envelopes=env, levels=levels, collection=collection,

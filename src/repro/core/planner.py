@@ -28,7 +28,7 @@ import numpy as np
 
 from repro.core import bounds, dtw
 from repro.core.paa import masked_znormalize, paa, znormalize
-from repro.core.types import EnvelopeParams, EnvelopeSet
+from repro.core.types import EnvelopeParams
 
 
 # --------------------------------------------------------------------------
@@ -135,33 +135,30 @@ def admit_query(q, p: EnvelopeParams) -> Tuple[np.ndarray, int]:
 # jitted lower-bound kernels
 # --------------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("seg_len", "nseg", "use_paa"))
-def env_lower_bounds(paa_lo, paa_hi, env: EnvelopeSet, breakpoints,
-                     seg_len: int, nseg: int, use_paa: bool):
-    """Lower bounds to every envelope (Eq. 5 / Eq. 8 unified)."""
-    if use_paa:
-        e_lo, e_hi = env.paa_lo, env.paa_hi
-    else:
-        e_lo, e_hi = bounds.envelope_breakpoint_bounds(env, breakpoints)
+@partial(jax.jit, static_argnames=("seg_len", "nseg"))
+def env_lower_bounds(paa_lo, paa_hi, e_lo, e_hi, valid,
+                     seg_len: int, nseg: int):
+    """Lower bounds to every envelope (Eq. 5 / Eq. 8 unified).
+
+    `e_lo`/`e_hi` (N, w) are the envelopes' intervals as
+    `UlisseIndex.lb_intervals` holds them; `valid` (N,) masks padding.
+    """
     d = bounds.interval_mindist(paa_lo, paa_hi, e_lo, e_hi, seg_len, nseg)
-    return jnp.where(env.valid, d, jnp.inf)
+    return jnp.where(valid, d, jnp.inf)
 
 
-@partial(jax.jit, static_argnames=("seg_len", "nseg", "use_paa"))
-def env_lower_bounds_batch(paa_lo, paa_hi, env: EnvelopeSet, breakpoints,
-                           seg_len: int, nseg: int, use_paa: bool):
+@partial(jax.jit, static_argnames=("seg_len", "nseg"))
+def env_lower_bounds_batch(paa_lo, paa_hi, e_lo, e_hi, valid,
+                           seg_len: int, nseg: int):
     """Lower bounds of a stacked (B, w) query batch to every envelope.
 
-    The envelope-side intervals (breakpoint lookups) are computed once
+    The envelope-side intervals are read as held (the index computes
+    its breakpoint intervals once per build, append, compact or open)
     and shared across the batch — the "shared plan" of the batched
     local backend.  Returns (B, N).
     """
-    if use_paa:
-        e_lo, e_hi = env.paa_lo, env.paa_hi
-    else:
-        e_lo, e_hi = bounds.envelope_breakpoint_bounds(env, breakpoints)
     d = bounds.interval_mindist(paa_lo, paa_hi, e_lo, e_hi, seg_len, nseg)
-    return jnp.where(env.valid[None, :], d, jnp.inf)
+    return jnp.where(valid[None, :], d, jnp.inf)
 
 
 @partial(jax.jit, static_argnames=("seg_len", "nseg"))
@@ -205,8 +202,9 @@ def plan_scan_order(index, pq: PreparedQuery,
     series are scanned with the same bsf pruning as bulk-loaded ones.
     """
     lbs = np.asarray(env_lower_bounds(
-        pq.paa_lo, pq.paa_hi, index.search_envelopes(), index.breakpoints,
-        index.params.seg_len, pq.nseg, use_paa_bounds), np.float64)
+        pq.paa_lo, pq.paa_hi, *index.lb_intervals(use_paa_bounds),
+        index.search_envelopes().valid, index.params.seg_len, pq.nseg),
+        np.float64)
     order = np.argsort(lbs)
     return order, lbs[order]
 

@@ -231,7 +231,8 @@ class EnvelopeSet:
 
     Shapes: N = number of envelopes, w = PAA segments.
       paa_lo / paa_hi : (N, w) float32 — real-valued L / U PAA bounds.
-      sym_lo / sym_hi : (N, w) int32   — iSAX(L) / iSAX(U) symbols.
+      sym_lo / sym_hi : (N, w) int32   — iSAX(L) / iSAX(U) symbols
+                        (numpy, on the host, in a UlisseIndex).
       series_id       : (N,)  int32    — source series in the Collection.
       anchor          : (N,)  int32    — first master offset `a`.
       n_master        : (N,)  int32    — number of valid masters (<= gamma+1).
@@ -269,8 +270,21 @@ class EnvelopeSet:
         return cls(*children)
 
 
+def array_module(x):
+    """numpy for a host array, jax.numpy otherwise — so a per-field
+    operation on an EnvelopeSet leaves each field where it lives (a
+    UlisseIndex keeps its symbols on the host)."""
+    return np if isinstance(x, np.ndarray) else jnp
+
+
 def concat_envelope_sets(sets) -> EnvelopeSet:
-    return jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs, axis=0), *sets)
+    """Row-concatenate EnvelopeSets; a field held on the host by any
+    part stays on the host."""
+    def cat(*xs):
+        if any(array_module(x) is np for x in xs):
+            return np.concatenate([np.asarray(x) for x in xs])
+        return jnp.concatenate(xs, axis=0)
+    return jax.tree_util.tree_map(cat, *sets)
 
 
 def concat_collections(a: Collection, b: Collection) -> Collection:

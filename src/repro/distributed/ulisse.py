@@ -300,8 +300,12 @@ def _shard_prelude(p, breakpoints, use_paa, mesh, axes, data, e_sid,
                       sym_hi=e_symhi, series_id=lsid, anchor=e_anc,
                       n_master=e_nm, valid=e_valid)
     nseg = p.query_segments(qlen)
-    lbs = planner.env_lower_bounds_batch(qb, qh, env, breakpoints,
-                                         p.seg_len, nseg, use_paa)
+    if use_paa:
+        e_lo, e_hi = e_paalo, e_paahi
+    else:
+        e_lo, e_hi = bounds.envelope_breakpoint_bounds(env, breakpoints)
+    lbs = planner.env_lower_bounds_batch(qb, qh, e_lo, e_hi, e_valid,
+                                         p.seg_len, nseg)
     return shard_idx, lsid, lbs
 
 

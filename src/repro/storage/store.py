@@ -25,7 +25,7 @@ from typing import Dict, List, Optional
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.index import BlockLevel, UlisseIndex
+from repro.core.index import SYMBOL_FIELDS, BlockLevel, UlisseIndex
 from repro.core.types import (Collection, EnvelopeParams, EnvelopeSet,
                               PageBlock)
 from repro.storage import format as fmt
@@ -359,8 +359,10 @@ def _save_envelope_set(tmp: str, group: str, env: EnvelopeSet,
 
 
 def _load_envelope_set(path: str, group: str, arrays: dict) -> EnvelopeSet:
+    # the symbols stay on the host, where a UlisseIndex keeps them
     return EnvelopeSet(*(
-        jnp.asarray(fmt.load_array(path, arrays[f"{group}/{field}"]))
+        (np.asarray if field in SYMBOL_FIELDS else jnp.asarray)(
+            fmt.load_array(path, arrays[f"{group}/{field}"]))
         for field in ENV_FIELDS))
 
 
