@@ -45,18 +45,27 @@ def host_rng(seed: int, stream: int) -> np.random.Generator:
     return np.random.default_rng([seed, stream])
 
 
-def make_collection(seed: int, num_series: int, series_len: int):
-    """(S, n) float32 random walks on the default device, from the seed."""
+def make_collection(seed: int, num_series: int, series_len: int,
+                    mesh=None):
+    """(S, n) float32 random walks from the seed: on the default device,
+    or row-sharded over `mesh`'s "data" axis, each chip making only its
+    own rows.  Both are the same walks, bit for bit: the random bits are
+    partitionable (`jax_threefry_partitionable`) and the sum runs along
+    each row."""
     import jax
     import jax.numpy as jnp
 
-    @jax.jit
+    kw = {}
+    if mesh is not None:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        kw["out_shardings"] = NamedSharding(mesh, P("data", None))
+
     def walk(key):
         steps = jax.random.normal(key, (num_series, series_len),
                                   jnp.float32)
         return jnp.cumsum(steps, axis=1)
 
-    return walk(device_key(seed, 0))
+    return jax.jit(walk, **kw)(device_key(seed, 0))
 
 
 @dataclasses.dataclass
@@ -81,8 +90,15 @@ def make_queries(data, seed: int, lengths: Sequence[int], noise: float,
     sids = np.array([rng.integers(0, s_count) for _ in lengths], np.int32)
     offs = np.array([rng.integers(0, n - ln + 1) for ln in lengths],
                     np.int32)
-    rows = np.asarray(jax.jit(lambda d, s: jnp.take(d, s, axis=0))(
-        data, jnp.asarray(sids)))
+    take = lambda d, s: jnp.take(d, s, axis=0)
+    sharding = data.sharding
+    if isinstance(sharding, jax.sharding.NamedSharding):
+        # rows of a collection sharded over a mesh of explicit axes: the
+        # gather has to be told where its result goes (every chip)
+        every_chip = jax.sharding.NamedSharding(sharding.mesh,
+                                                jax.sharding.PartitionSpec())
+        take = lambda d, s: d.at[s].get(out_sharding=every_chip)
+    rows = np.asarray(jax.jit(take)(data, jnp.asarray(sids)))
     out = []
     for i, ln in enumerate(lengths):
         vals = rows[i, offs[i]:offs[i] + ln].astype(np.float32)
@@ -102,16 +118,17 @@ def poisson_due_times(rate: float, seconds: float, work_seed: int
     return starts * (seconds / gaps.sum())
 
 
-def make_work(config, traffic, seed: int, seconds: float):
+def make_work(config, traffic, seed: int, seconds: float, mesh=None):
     """(collection, queries in arrival order, due times) of one run.
 
-    The collection, the queries (each length of the traffic's list as
+    The collection (row-sharded over `mesh` where one is given), the
+    queries (each length of the traffic's list as
     often as the others, to within one) and the due times (None for a
     closed loop, which sends from a pool of `pool` queries) come from
     the traffic's `work_seed`; the run seed orders the queries."""
     work = traffic["work_seed"]
     data = make_collection(work, config["num_series"],
-                           config["series_len"])
+                           config["series_len"], mesh)
     if traffic["kind"] == "poisson":
         due = poisson_due_times(traffic["rate_per_s"], seconds, work)
         count = len(due)

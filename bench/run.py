@@ -24,6 +24,13 @@ answers' `SearchStats` and a profiler trace of the window.  The last
 line of stdout is the JSON result; the last lines of stderr are the
 numbers compared, each beside its limit.  Without a TPU, or with fewer
 chips than the cell asks for, it exits 3 and prints no result.
+
+A configuration with `"shards": s` (1 when absent) row-shards the
+collection over a ("data",) mesh of the cell's first s chips and serves
+it from `UlisseEngine.distributed`; a cell whose `chips` is not its
+configuration's `shards` exits 2 before any work.  Memory is read over
+every chip of the cell: `hbm_bytes_per_data_byte` sums their growth,
+`memory_peak_bytes` is the fullest chip's peak.
 """
 from __future__ import annotations
 
@@ -230,13 +237,38 @@ def pick_sample(reqs, t_close, n, seed):
 
 # -- the run ----------------------------------------------------------------
 
+def shards_of(config) -> int:
+    return int(config.get("shards", 1))
+
+
+def cell_mesh(config):
+    """The ("data",) mesh of the first `shards` chips that the
+    configuration shards its collection over; None for one chip."""
+    shards = shards_of(config)
+    if shards == 1:
+        return None
+    import jax
+    return jax.make_mesh((shards,), ("data",),
+                         devices=jax.devices()[:shards])
+
+
 def build_engine(config, data):
     from repro.core import Collection, EnvelopeParams, UlisseEngine
     p = EnvelopeParams(lmin=config["lmin"], lmax=config["lmax"],
                        gamma=config["gamma"], seg_len=config["seg_len"],
                        card=config["card"], znorm=config["znorm"])
-    return UlisseEngine.from_collection(Collection.from_array(data), p,
-                                        max_batch=config["engine_max_batch"])
+    mesh = cell_mesh(config)
+    if mesh is None:
+        return UlisseEngine.from_collection(
+            Collection.from_array(data), p,
+            max_batch=config["engine_max_batch"])
+    return UlisseEngine.distributed(mesh, p, data,
+                                    max_batch=config["engine_max_batch"])
+
+
+def chip_bytes(devices, key="bytes_in_use") -> list:
+    """`key` of each chip's memory stats (0 where a chip keeps none)."""
+    return [(d.memory_stats() or {}).get(key, 0) for d in devices]
 
 
 def query_spec(config):
@@ -245,26 +277,31 @@ def query_spec(config):
                      r=config.get("r", 0), mode=config["mode"])
 
 
-def run_cell(bench, cell, config, traffic, seed, seconds, trace, device):
-    """Everything after the look for a chip: returns the result dict."""
+def run_cell(bench, cell, config, traffic, seed, seconds, trace, devices):
+    """Everything after the look for chips (`devices`: the cell's
+    chips): returns the result dict."""
     import jax
     from bench import check, gen, reference
     from repro import obs
     from repro.serve import ServeConfig, UlisseServer
 
+    device = devices[0]
     s_count, n = config["num_series"], config["series_len"]
-    mem = lambda: (device.memory_stats() or {}).get("bytes_in_use", 0)
-    mem0 = mem()
-    data, queries, due = gen.make_work(config, traffic, seed, seconds)
+    mem0 = chip_bytes(devices)
+    data, queries, due = gen.make_work(config, traffic, seed, seconds,
+                                       cell_mesh(config))
     data.block_until_ready()
     engine = build_engine(config, data)
     jax.block_until_ready(list(engine.device_arrays().values()))
     del data
     gc.collect()
-    mem1 = mem()
-    hbm_ratio = (mem1 - mem0) / (s_count * n * 4)
-    log(f"collection + index: {mem1 - mem0} bytes on the chip for "
-        f"{s_count * n * 4} bytes of series")
+    mem1 = chip_bytes(devices)
+    held = sum(mem1) - sum(mem0)
+    hbm_ratio = held / (s_count * n * 4)
+    log(f"collection + index: {held} bytes on {len(devices)} chip(s) "
+        f"for {s_count * n * 4} bytes of series")
+    for d, b0, b1 in zip(devices, mem0, mem1):
+        log(f"  chip {d.id}: {b1 - b0} bytes resident")
 
     srv = traffic["server"]
     server = UlisseServer(engine, query_spec(config),
@@ -293,7 +330,10 @@ def run_cell(bench, cell, config, traffic, seed, seconds, trace, device):
     if prof is not None:
         prof.stop()
     server.close(drain=False)
-    peak = (device.memory_stats() or {}).get("peak_bytes_in_use", 0)
+    peaks = chip_bytes(devices, "peak_bytes_in_use")
+    for d, b in zip(devices, peaks):
+        log(f"  chip {d.id}: peak_bytes_in_use {b}")
+    peak = max(peaks)
     spans = [s.as_dict() for s in tracer.drain()] if trace else []
     tracer.configure(enabled=False)
 
@@ -447,6 +487,11 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
     bench, cell, config, traffic = load_spec(args.workload)
+    if cell["chips"] != shards_of(config):
+        log(f"bench: cell {cell['name']} asks for {cell['chips']} chip(s) "
+            f"but its config {cell['config']} shards over "
+            f"{shards_of(config)}")
+        return 2
 
     # one fixed cache inside the checkout, whatever the environment says
     os.environ["JAX_COMPILATION_CACHE_DIR"] = str(CACHE_DIR)
@@ -462,7 +507,8 @@ def main(argv=None) -> int:
         return 3
     sys.path[:0] = [str(ROOT), str(ROOT / "src")]
     result = run_cell(bench, cell, config, traffic, args.seed,
-                      args.seconds, bool(args.trace), devices[0])
+                      args.seconds, bool(args.trace),
+                      devices[:cell["chips"]])
     print_checks(result["checks"])
     print(json.dumps(result), flush=True)
     return 0
